@@ -30,8 +30,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from .compat import axis_size, shard_map as _shard_map
-
 
 def _bucket_by_device(x, expert_idx, gate, num_devices: int,
                       experts_per_device: int, cap: int):
@@ -69,7 +67,7 @@ def a2a_route_and_compute(x, router_w, expert_fn, *, axis_name: str,
     """Runs inside shard_map: x (t_local, d) token shard; router_w (d, E)
     replicated; expert_fn(local_expert_id, tokens) applies THIS device's
     expert. Returns (t_local, d) combined outputs."""
-    nd = axis_size(axis_name)
+    nd = jax.lax.axis_size(axis_name)
     epd = num_experts // nd
     t, d = x.shape
     cap = max(int(capacity_factor * top_k * t / nd), 1)
@@ -128,7 +126,7 @@ def make_a2a_moe(mesh, num_experts: int, top_k: int, d_model: int,
             capacity_factor=capacity_factor,
         )
 
-    return _shard_map(
+    return jax.shard_map(
         fn, mesh=mesh,
         in_specs=(
             P(axis_name, None),  # tokens sharded

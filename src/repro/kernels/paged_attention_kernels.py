@@ -39,9 +39,11 @@ Tiling: one grid step consumes ``paged_block_kv`` rows of a pool block
 ``block_size`` exceeds the VMEM-friendly 128). The last dim of a KV tile
 is ``head_dim`` (< 128 on most configs), so lanes are padded on real
 TPUs — acceptable for a bandwidth-bound decode kernel whose tiles are
-resident for exactly one recurrence step. Validated in interpret mode
-against the gather path (CPU CI runs it interpreted via the lazy
-``KernelConfig.resolve_interpret`` policy, same as the routing kernels).
+resident for exactly one recurrence step. On the CPU the tests run it in
+interpret mode against the gather path (the lazy
+``KernelConfig.resolve_interpret`` policy, same as the routing kernels);
+tests/test_tpu_compile.py compiles it for a described v5e, and
+chip_smoke.py serves through it on one.
 """
 from __future__ import annotations
 
@@ -76,7 +78,7 @@ def _paged_decode_kernel(tables_ref, qpos_ref, q_ref, k_ref, v_ref, pos_ref,
     q = q_ref[0].astype(dt)      # (H, Dk)
     k = k_ref[0].astype(dt)      # (bkv, G, Dk)
     v = v_ref[0].astype(dt)      # (bkv, G, Dv)
-    kp = pos_ref[0]              # (bkv,) int32; -1 = invalid
+    kp = pos_ref[0, 0]           # (bkv,) int32; -1 = invalid
     qp = qpos_ref[b]             # scalar; < 0 = inactive row
 
     d = q.shape[-1]
@@ -159,9 +161,13 @@ def paged_decode_attend(q, k_pool, v_pool, pos_pool, tables, q_pos, *,
                 lambda b, jt, tables, qpos: (tables[b, jt // sub],
                                              jt % sub, 0, 0),
             ),
+            # positions ride as (num_blocks, 1, block_size) rows: Mosaic
+            # tiles the last two block dims by (8, 128), which a (1, bkv)
+            # block of the 2-D pool fails.
             pl.BlockSpec(
-                (1, bkv),
-                lambda b, jt, tables, qpos: (tables[b, jt // sub], jt % sub),
+                (1, 1, bkv),
+                lambda b, jt, tables, qpos: (tables[b, jt // sub], 0,
+                                             jt % sub),
             ),
         ],
         out_specs=pl.BlockSpec(
@@ -181,4 +187,4 @@ def paged_decode_attend(q, k_pool, v_pool, pos_pool, tables, q_pos, *,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, dv), q.dtype),
         interpret=cfg.resolve_interpret(),
-    )(tables, q_pos, q, k_pool, v_pool, pos_pool)
+    )(tables, q_pos, q, k_pool, v_pool, pos_pool[:, None, :])

@@ -59,8 +59,15 @@ Phi arrives pre-normalized (scale * l2norm(phi) is O(d·S), done once
 outside); X is l2-normalized inside the kernel (it is re-read every pass —
 normalizing outside would double-read X from HBM).
 
-Validated in interpret mode against ref.py (CPU has no MXU; TPU is the
-target), forward allclose and gradients allclose to the ref VJP.
+Softmax stats travel as lane-dense ``(b, 1, n)`` rows (``_stat_spec``)
+and the full-length per-token scratch as one ``(1, bt)`` row per token
+block: Mosaic refuses a ``(1, blk)`` block of a ``(b, n)`` array and a
+1-D scratch written at an offset it cannot prove tile-aligned.
+
+On the CPU the tests run these kernels in interpret mode against ref.py
+(forward allclose, gradients allclose to the ref VJP), which applies none
+of Mosaic's tiling rules; tests/test_tpu_compile.py compiles them for a
+described v5e, and chip_smoke.py runs them on one.
 """
 from __future__ import annotations
 
@@ -105,6 +112,16 @@ def _l2n_bwd(x, dxn, dt):
     return r[:, None] * dxn - (r * r * inv_n * proj)[:, None] * x
 
 
+def _vec(ref):
+    """A stat block ``(1, 1, n)`` or row scratch ``(1, n)`` as an (n,)
+    vector."""
+    return ref[...].reshape(-1)
+
+
+def _put(ref, v):
+    ref[...] = v.reshape(ref.shape).astype(ref.dtype)
+
+
 def _pad_to(x, size, axis, value=0.0):
     pad = size - x.shape[axis]
     if pad == 0:
@@ -112,6 +129,19 @@ def _pad_to(x, size, axis, value=0.0):
     widths = [(0, 0)] * x.ndim
     widths[axis] = (0, pad)
     return jnp.pad(x, widths, constant_values=value)
+
+
+def _stat_in(v, size, dt, value=0.0):
+    """(b, n) softmax stats -> padded (b, 1, size) kernel operand."""
+    return _pad_to(v.astype(dt), size, axis=1, value=value)[:, None, :]
+
+
+def _stat_spec(blk, index_map):
+    """Stat operands are (b, 1, n) lane-dense rows; a block is (1, 1, blk).
+    Mosaic tiles the last two block dims by (8, 128): a (1, blk) block of a
+    (b, n) array is refused unless b == 1, and (1, blk) of a (b, 1, n) one
+    is accepted for blk % 128 == 0 or blk == n."""
+    return pl.BlockSpec((1, 1, blk), index_map)
 
 
 def _grid_sizes(m, s, cfg: KernelConfig):
@@ -136,12 +166,12 @@ def _routing_fwd_kernel(x_ref, phi_ref, slots_ref, dmx_ref, dden_ref,
         smx[...] = jnp.full_like(smx, _NEG)
         sden[...] = jnp.zeros_like(sden)
 
-    tok = pl.ds(jt * bt, bt)
+    cmx_tok, cden_tok = cmx_all.at[jt], cden_all.at[jt]
 
     @pl.when(js == 0)
     def _init_token_stats():
-        cmx_all[tok] = jnp.full((bt,), _NEG, dt)
-        cden_all[tok] = jnp.zeros((bt,), dt)
+        cmx_tok[...] = jnp.full(cmx_tok.shape, _NEG, dt)
+        cden_tok[...] = jnp.zeros(cden_tok.shape, dt)
 
     x, _xn, logits = _logits_tile(x_ref, phi_ref, dt)
     row = jt * bt + jax.lax.broadcasted_iota(jnp.int32, logits.shape, 0)
@@ -150,34 +180,35 @@ def _routing_fwd_kernel(x_ref, phi_ref, slots_ref, dmx_ref, dden_ref,
     lg_c = jnp.where(col < s_valid, logits, _NEG)  # combine: mask pad slots
 
     # dispatch direction: online softmax over tokens (inner jt loop)
-    m_old = smx[...]
+    m_old = _vec(smx)
     m_new = jnp.maximum(m_old, lg_d.max(axis=0))  # (bs,)
     corr = jnp.exp(m_old - m_new)
     p = jnp.exp(lg_d - m_new[None, :])  # (bt, bs)
-    sden[...] = sden[...] * corr + p.sum(axis=0)
+    _put(sden, _vec(sden) * corr + p.sum(axis=0))
     # acc: (bs, d) += pᵀ @ x  (raw x — the paper mixes unnormalized tokens)
     acc[...] = acc[...] * corr[:, None] + _dot(p, x, ((0,), (0,)), dt)
-    smx[...] = m_new
+    _put(smx, m_new)
 
     # combine direction: online (max, denom) over slots (outer js loop);
-    # running values land in the full-length O(m) scratch and are written
-    # out every visit (the (jb, jt) output block is revisited per js, so
-    # the buffer cannot be trusted to persist — last write wins).
-    cm_old = cmx_all[tok]
+    # running values land in the full-length O(m) scratch (one (1, bt) row
+    # per token block, indexed on its leading axis) and are written out
+    # every visit (the (jb, jt) output block is revisited per js, so the
+    # buffer cannot be trusted to persist — last write wins).
+    cm_old = _vec(cmx_tok)
     cm_new = jnp.maximum(cm_old, lg_c.max(axis=1))  # (bt,)
     ccorr = jnp.exp(cm_old - cm_new)
-    cden_new = cden_all[tok] * ccorr + jnp.exp(
+    cden_new = _vec(cden_tok) * ccorr + jnp.exp(
         lg_c - cm_new[:, None]).sum(axis=1)
-    cmx_all[tok] = cm_new
-    cden_all[tok] = cden_new
-    cmx_ref[0] = cm_new.astype(cmx_ref.dtype)
-    cden_ref[0] = cden_new.astype(cden_ref.dtype)
+    _put(cmx_tok, cm_new)
+    _put(cden_tok, cden_new)
+    _put(cmx_ref, cm_new)
+    _put(cden_ref, cden_new)
 
     @pl.when(jt == nt - 1)
     def _finish_slot_block():
-        slots_ref[0] = (acc[...] / sden[...][:, None]).astype(slots_ref.dtype)
-        dmx_ref[0] = smx[...].astype(dmx_ref.dtype)
-        dden_ref[0] = sden[...].astype(dden_ref.dtype)
+        slots_ref[0] = (acc[...] / _vec(sden)[:, None]).astype(slots_ref.dtype)
+        _put(dmx_ref, _vec(smx))
+        _put(dden_ref, _vec(sden))
 
 
 def routing_fwd_pallas(x, phi_n, cfg: KernelConfig = KernelConfig()):
@@ -195,11 +226,13 @@ def routing_fwd_pallas(x, phi_n, cfg: KernelConfig = KernelConfig()):
     grid = (b, s_pad // bs, m_pad // bt)
     out_shapes = (
         jax.ShapeDtypeStruct((b, s_pad, d), x.dtype),  # slots
-        jax.ShapeDtypeStruct((b, s_pad), dt),  # dispatch max
-        jax.ShapeDtypeStruct((b, s_pad), dt),  # dispatch denom
-        jax.ShapeDtypeStruct((b, m_pad), dt),  # combine max
-        jax.ShapeDtypeStruct((b, m_pad), dt),  # combine denom
+        jax.ShapeDtypeStruct((b, 1, s_pad), dt),  # dispatch max
+        jax.ShapeDtypeStruct((b, 1, s_pad), dt),  # dispatch denom
+        jax.ShapeDtypeStruct((b, 1, m_pad), dt),  # combine max
+        jax.ShapeDtypeStruct((b, 1, m_pad), dt),  # combine denom
     )
+    sstat = _stat_spec(bs, lambda jb, js, jt: (jb, 0, js))
+    tstat = _stat_spec(bt, lambda jb, js, jt: (jb, 0, jt))
     slots, dmx, dden, cmx, cden = pl.pallas_call(
         functools.partial(_routing_fwd_kernel, m_valid=m, s_valid=s,
                           bt=bt, bs=bs, dt=dt),
@@ -210,23 +243,20 @@ def routing_fwd_pallas(x, phi_n, cfg: KernelConfig = KernelConfig()):
         ],
         out_specs=(
             pl.BlockSpec((1, bs, d), lambda jb, js, jt: (jb, js, 0)),
-            pl.BlockSpec((1, bs), lambda jb, js, jt: (jb, js)),
-            pl.BlockSpec((1, bs), lambda jb, js, jt: (jb, js)),
-            pl.BlockSpec((1, bt), lambda jb, js, jt: (jb, jt)),
-            pl.BlockSpec((1, bt), lambda jb, js, jt: (jb, jt)),
+            sstat, sstat, tstat, tstat,
         ),
         out_shape=out_shapes,
         scratch_shapes=[
             pltpu.VMEM((bs, d), dt),  # slot-mix accumulator
-            pltpu.VMEM((bs,), dt),  # dispatch running max
-            pltpu.VMEM((bs,), dt),  # dispatch running denom
-            pltpu.VMEM((m_pad,), dt),  # combine running max (all tokens)
-            pltpu.VMEM((m_pad,), dt),  # combine running denom (all tokens)
+            pltpu.VMEM((1, bs), dt),  # dispatch running max
+            pltpu.VMEM((1, bs), dt),  # dispatch running denom
+            pltpu.VMEM((m_pad // bt, 1, bt), dt),  # combine running max
+            pltpu.VMEM((m_pad // bt, 1, bt), dt),  # combine running denom
         ],
         interpret=cfg.resolve_interpret(),
     )(x, phi_n)
-    return (slots[:, :s], (dmx[:, :s], dden[:, :s]),
-            (cmx[:, :m], cden[:, :m]))
+    return (slots[:, :s], (dmx[:, 0, :s], dden[:, 0, :s]),
+            (cmx[:, 0, :m], cden[:, 0, :m]))
 
 
 # ---------------------------------------------------------------------------
@@ -246,12 +276,12 @@ def _routing_health_kernel(x_ref, phi_ref, dmx_ref, dden_ref, cmx_ref,
         dent_acc[...] = jnp.zeros_like(dent_acc)
         imp_acc[...] = jnp.zeros_like(imp_acc)
 
-    tok = pl.ds(jt * bt, bt)
+    cent_tok, contrib_tok = cent_all.at[jt], contrib_all.at[jt]
 
     @pl.when(js == 0)
     def _init_token_acc():
-        cent_all[tok] = jnp.zeros((bt,), dt)
-        contrib_all[tok] = jnp.zeros((bt,), dt)
+        cent_tok[...] = jnp.zeros(cent_tok.shape, dt)
+        contrib_tok[...] = jnp.zeros(contrib_tok.shape, dt)
 
     _x, _xn, logits = _logits_tile(x_ref, phi_ref, dt)
     row = jt * bt + jax.lax.broadcasted_iota(jnp.int32, logits.shape, 0)
@@ -266,29 +296,29 @@ def _routing_health_kernel(x_ref, phi_ref, dmx_ref, dden_ref, cmx_ref,
     # times a finite shifted logit). Pad-slot columns carry garbage weights
     # (their stats are (0, 1) padding); they are masked out of the
     # token-contribution row sums and sliced off the per-slot outputs.
-    ln_d = lg_d - dmx_ref[0][None, :] - jnp.log(dden_ref[0])[None, :]
+    ln_d = lg_d - _vec(dmx_ref)[None, :] - jnp.log(_vec(dden_ref))[None, :]
     d_w = jnp.exp(ln_d)
-    dent_acc[...] = dent_acc[...] - jnp.sum(d_w * ln_d, axis=0)
-    contrib_new = contrib_all[tok] + jnp.sum(
+    _put(dent_acc, _vec(dent_acc) - jnp.sum(d_w * ln_d, axis=0))
+    contrib_new = _vec(contrib_tok) + jnp.sum(
         jnp.where(valid_col, d_w, 0.0), axis=1)
-    contrib_all[tok] = contrib_new
-    contrib_ref[0] = contrib_new.astype(contrib_ref.dtype)
+    _put(contrib_tok, contrib_new)
+    _put(contrib_ref, contrib_new)
 
     # combine direction: per-token entropy (full-length scratch, written out
     # every visit — last write wins, same as the fwd kernel's stats) and
     # per-slot importance (pad-token rows masked out of the column sums).
-    ln_c = lg_c - cmx_ref[0][:, None] - jnp.log(cden_ref[0])[:, None]
+    ln_c = lg_c - _vec(cmx_ref)[:, None] - jnp.log(_vec(cden_ref))[:, None]
     c_w = jnp.exp(ln_c)
-    cent_new = cent_all[tok] - jnp.sum(c_w * ln_c, axis=1)
-    cent_all[tok] = cent_new
-    cent_ref[0] = cent_new.astype(cent_ref.dtype)
-    imp_acc[...] = imp_acc[...] + jnp.sum(
-        jnp.where(row < m_valid, c_w, 0.0), axis=0)
+    cent_new = _vec(cent_tok) - jnp.sum(c_w * ln_c, axis=1)
+    _put(cent_tok, cent_new)
+    _put(cent_ref, cent_new)
+    _put(imp_acc, _vec(imp_acc) + jnp.sum(
+        jnp.where(row < m_valid, c_w, 0.0), axis=0))
 
     @pl.when(jt == nt - 1)
     def _finish_slot_block():
-        dent_ref[0] = dent_acc[...].astype(dent_ref.dtype)
-        imp_ref[0] = imp_acc[...].astype(imp_ref.dtype)
+        _put(dent_ref, _vec(dent_acc))
+        _put(imp_ref, _vec(imp_acc))
 
 
 def routing_health_pallas(x, phi_n, d_stats, c_stats,
@@ -318,12 +348,12 @@ def routing_health_pallas(x, phi_n, d_stats, c_stats,
     phi_n = _pad_to(phi_n, s_pad, axis=1)
     # (max=0, denom=1) stat padding keeps every padded tile finite; padded
     # rows/columns are masked out of all four reductions above.
-    dmx = _pad_to(dmx.astype(dt), s_pad, axis=1)
-    dden = _pad_to(dden.astype(dt), s_pad, axis=1, value=1.0)
-    cmx = _pad_to(cmx.astype(dt), m_pad, axis=1)
-    cden = _pad_to(cden.astype(dt), m_pad, axis=1, value=1.0)
-    sstat = pl.BlockSpec((1, bs), lambda jb, js, jt: (jb, js))
-    tstat = pl.BlockSpec((1, bt), lambda jb, js, jt: (jb, jt))
+    dmx = _stat_in(dmx, s_pad, dt)
+    dden = _stat_in(dden, s_pad, dt, value=1.0)
+    cmx = _stat_in(cmx, m_pad, dt)
+    cden = _stat_in(cden, m_pad, dt, value=1.0)
+    sstat = _stat_spec(bs, lambda jb, js, jt: (jb, 0, js))
+    tstat = _stat_spec(bt, lambda jb, js, jt: (jb, 0, jt))
     dent, imp, cent, contrib = pl.pallas_call(
         functools.partial(_routing_health_kernel, m_valid=m, s_valid=s,
                           bt=bt, bs=bs, dt=dt),
@@ -335,20 +365,21 @@ def routing_health_pallas(x, phi_n, d_stats, c_stats,
         ],
         out_specs=(sstat, sstat, tstat, tstat),
         out_shape=(
-            jax.ShapeDtypeStruct((b, s_pad), dt),
-            jax.ShapeDtypeStruct((b, s_pad), dt),
-            jax.ShapeDtypeStruct((b, m_pad), dt),
-            jax.ShapeDtypeStruct((b, m_pad), dt),
+            jax.ShapeDtypeStruct((b, 1, s_pad), dt),
+            jax.ShapeDtypeStruct((b, 1, s_pad), dt),
+            jax.ShapeDtypeStruct((b, 1, m_pad), dt),
+            jax.ShapeDtypeStruct((b, 1, m_pad), dt),
         ),
         scratch_shapes=[
-            pltpu.VMEM((bs,), dt),  # dispatch entropy accumulator
-            pltpu.VMEM((bs,), dt),  # combine importance accumulator
-            pltpu.VMEM((m_pad,), dt),  # combine entropy (all tokens)
-            pltpu.VMEM((m_pad,), dt),  # token contribution (all tokens)
+            pltpu.VMEM((1, bs), dt),  # dispatch entropy accumulator
+            pltpu.VMEM((1, bs), dt),  # combine importance accumulator
+            pltpu.VMEM((m_pad // bt, 1, bt), dt),  # combine entropy
+            pltpu.VMEM((m_pad // bt, 1, bt), dt),  # token contribution
         ],
         interpret=cfg.resolve_interpret(),
     )(x, phi_n, dmx, dden, cmx, cden)
-    return dent[:, :s], imp[:, :s], cent[:, :m], contrib[:, :m]
+    return (dent[:, 0, :s], imp[:, 0, :s], cent[:, 0, :m],
+            contrib[:, 0, :m])
 
 
 # ---------------------------------------------------------------------------
@@ -379,25 +410,25 @@ def _combine_kernel(x_ref, phi_ref, ys_ref, cmx_ref, cden_ref, out_ref,
     ys = ys_ref[0].astype(dt)  # (bs, d)
 
     if online:
-        m_old = mx[...]
+        m_old = _vec(mx)
         m_new = jnp.maximum(m_old, lg_c.max(axis=1))  # (bt,)
         corr = jnp.exp(m_old - m_new)
         p = jnp.exp(lg_c - m_new[:, None])
-        den[...] = den[...] * corr + p.sum(axis=1)
+        _put(den, _vec(den) * corr + p.sum(axis=1))
         acc[...] = acc[...] * corr[:, None] + _dot(p, ys, ((1,), (0,)), dt)
-        mx[...] = m_new
+        _put(mx, m_new)
     else:
         # exact final (max, denom) saved by routing_fwd: p ≤ 1, no rescale
-        p = jnp.exp(lg_c - cmx_ref[0][:, None])
+        p = jnp.exp(lg_c - _vec(cmx_ref)[:, None])
         acc[...] = acc[...] + _dot(p, ys, ((1,), (0,)), dt)
 
     @pl.when(js == ns - 1)
     def _finish():
-        d_final = den[...] if online else cden_ref[0].astype(dt)
+        d_final = _vec(den) if online else _vec(cden_ref).astype(dt)
         out_ref[0] = (acc[...] / d_final[:, None]).astype(out_ref.dtype)
         if online:
-            cmx_out[0] = mx[...].astype(cmx_out.dtype)
-            cden_out[0] = den[...].astype(cden_out.dtype)
+            _put(cmx_out, _vec(mx))
+            _put(cden_out, _vec(den))
 
 
 def _combine_call(x, phi_n, ys, c_stats, cfg: KernelConfig):
@@ -415,7 +446,7 @@ def _combine_call(x, phi_n, ys, c_stats, cfg: KernelConfig):
         pl.BlockSpec((d, bs), lambda jb, jt, js: (0, js)),
         pl.BlockSpec((1, bs, d), lambda jb, jt, js: (jb, js, 0)),
     ]
-    stat_spec = pl.BlockSpec((1, bt), lambda jb, jt, js: (jb, jt))
+    stat_spec = _stat_spec(bt, lambda jb, jt, js: (jb, 0, jt))
     y_spec = pl.BlockSpec((1, bt, d), lambda jb, jt, js: (jb, jt, 0))
     y_shape = jax.ShapeDtypeStruct((b, m_pad, d), x.dtype)
     if online:
@@ -426,20 +457,20 @@ def _combine_call(x, phi_n, ys, c_stats, cfg: KernelConfig):
             in_specs=in_specs,
             out_specs=(y_spec, stat_spec, stat_spec),
             out_shape=(y_shape,
-                       jax.ShapeDtypeStruct((b, m_pad), dt),
-                       jax.ShapeDtypeStruct((b, m_pad), dt)),
+                       jax.ShapeDtypeStruct((b, 1, m_pad), dt),
+                       jax.ShapeDtypeStruct((b, 1, m_pad), dt)),
             scratch_shapes=[
                 pltpu.VMEM((bt, d), dt),
-                pltpu.VMEM((bt,), dt),
-                pltpu.VMEM((bt,), dt),
+                pltpu.VMEM((1, bt), dt),
+                pltpu.VMEM((1, bt), dt),
             ],
             interpret=cfg.resolve_interpret(),
         )(x, phi_n, ys)
         y, cmx, cden = out
-        return y[:, :m], (cmx[:, :m], cden[:, :m])
+        return y[:, :m], (cmx[:, 0, :m], cden[:, 0, :m])
     cmx, cden = c_stats
-    cmx = _pad_to(cmx.astype(dt), m_pad, axis=1)
-    cden = _pad_to(cden.astype(dt), m_pad, axis=1, value=1.0)
+    cmx = _stat_in(cmx, m_pad, dt)
+    cden = _stat_in(cden, m_pad, dt, value=1.0)
     y = pl.pallas_call(
         functools.partial(_combine_kernel, s_valid=s, bs=bs, dt=dt,
                           online=False),
@@ -476,10 +507,10 @@ def _dispatch_bwd_tile(x_ref, phi_ref, g_ref, dmx_ref, dden_ref, sig_ref,
     x, xn, logits = _logits_tile(x_ref, phi_ref, dt)
     row = jt * bt + jax.lax.broadcasted_iota(jnp.int32, logits.shape, 0)
     lg_d = jnp.where(row < m_valid, logits, _NEG)
-    d_w = jnp.exp(lg_d - dmx_ref[0][None, :]) / dden_ref[0][None, :]
+    d_w = jnp.exp(lg_d - _vec(dmx_ref)[None, :]) / _vec(dden_ref)[None, :]
     g = g_ref[0].astype(dt)  # (bs, d)
     d_dw = _dot(x, g, ((1,), (1,)), dt)  # (bt, bs) = x · g_s
-    d_lg = d_w * (d_dw - sig_ref[0][None, :])
+    d_lg = d_w * (d_dw - _vec(sig_ref)[None, :])
     return x, xn, d_w, d_lg, g
 
 
@@ -546,13 +577,13 @@ def dispatch_bwd_pallas(x, phi_n, g_slots, d_stats, slots,
     g_p = _pad_to(g_slots, s_pad, axis=1)
     # pad stats with (max=0, denom=1): padded-column D tiles stay finite and
     # are multiplied only by zero-padded g/σ, so they never contribute.
-    dmx_p = _pad_to(dmx.astype(dt), s_pad, axis=1)
-    dden_p = _pad_to(dden.astype(dt), s_pad, axis=1, value=1.0)
-    sig_p = _pad_to(sigma, s_pad, axis=1)
+    dmx_p = _stat_in(dmx, s_pad, dt)
+    dden_p = _stat_in(dden, s_pad, dt, value=1.0)
+    sig_p = _stat_in(sigma, s_pad, dt)
     args = (x_p, phi_p, g_p, dmx_p, dden_p, sig_p)
 
     x_spec_t = pl.BlockSpec((1, bt, d), lambda jb, jt, js: (jb, jt, 0))
-    sstat_t = pl.BlockSpec((1, bs), lambda jb, jt, js: (jb, js))
+    sstat_t = _stat_spec(bs, lambda jb, jt, js: (jb, 0, js))
     dx = pl.pallas_call(
         functools.partial(_dispatch_bwd_dx_kernel, m_valid=m, bt=bt, dt=dt),
         grid=(b, m_pad // bt, s_pad // bs),
@@ -568,7 +599,7 @@ def dispatch_bwd_pallas(x, phi_n, g_slots, d_stats, slots,
         interpret=cfg.resolve_interpret(),
     )(*args)
 
-    sstat_s = pl.BlockSpec((1, bs), lambda js, jb, jt: (jb, js))
+    sstat_s = _stat_spec(bs, lambda js, jb, jt: (jb, 0, js))
     dphi = pl.pallas_call(
         functools.partial(_dispatch_bwd_dphi_kernel, m_valid=m, bt=bt, dt=dt),
         grid=(s_pad // bs, b, m_pad // bt),
@@ -597,11 +628,11 @@ def _combine_bwd_tile(x_ref, phi_ref, ys_ref, g_ref, cmx_ref, cden_ref,
     _x, xn, logits = _logits_tile(x_ref, phi_ref, dt)
     col = js * bs + jax.lax.broadcasted_iota(jnp.int32, logits.shape, 1)
     lg_c = jnp.where(col < s_valid, logits, _NEG)
-    c_w = jnp.exp(lg_c - cmx_ref[0][:, None]) / cden_ref[0][:, None]
+    c_w = jnp.exp(lg_c - _vec(cmx_ref)[:, None]) / _vec(cden_ref)[:, None]
     g = g_ref[0].astype(dt)  # (bt, d)
     ys = ys_ref[0].astype(dt)  # (bs, d)
     d_cw = _dot(g, ys, ((1,), (1,)), dt)  # (bt, bs) = g_i · ys_s
-    d_lg = c_w * (d_cw - rho_ref[0][:, None])
+    d_lg = c_w * (d_cw - _vec(rho_ref)[:, None])
     return xn, c_w, d_lg, g
 
 
@@ -675,13 +706,13 @@ def combine_bwd_pallas(x, phi_n, ys, g, c_stats, y,
     g_p = _pad_to(g, m_pad, axis=1)
     # (max=0, denom=1) padding keeps padded-row C tiles finite; they meet
     # only zero-padded g/ρ rows, so dL and every accumulator stay exact.
-    cmx_p = _pad_to(cmx.astype(dt), m_pad, axis=1)
-    cden_p = _pad_to(cden.astype(dt), m_pad, axis=1, value=1.0)
-    rho_p = _pad_to(rho, m_pad, axis=1)
+    cmx_p = _stat_in(cmx, m_pad, dt)
+    cden_p = _stat_in(cden, m_pad, dt, value=1.0)
+    rho_p = _stat_in(rho, m_pad, dt)
     args = (x_p, phi_p, ys_p, g_p, cmx_p, cden_p, rho_p)
 
     x_spec_t = pl.BlockSpec((1, bt, d), lambda jb, jt, js: (jb, jt, 0))
-    tstat_t = pl.BlockSpec((1, bt), lambda jb, jt, js: (jb, jt))
+    tstat_t = _stat_spec(bt, lambda jb, jt, js: (jb, 0, jt))
     dx = pl.pallas_call(
         functools.partial(_combine_bwd_dx_kernel, s_valid=s, bs=bs, dt=dt),
         grid=(b, m_pad // bt, s_pad // bs),
@@ -698,7 +729,7 @@ def combine_bwd_pallas(x, phi_n, ys, g, c_stats, y,
     )(*args)
 
     x_spec_s = pl.BlockSpec((1, bt, d), lambda js, jb, jt: (jb, jt, 0))
-    tstat_s = pl.BlockSpec((1, bt), lambda js, jb, jt: (jb, jt))
+    tstat_s = _stat_spec(bt, lambda js, jb, jt: (jb, 0, jt))
     dys, dphi = pl.pallas_call(
         functools.partial(_combine_bwd_dys_dphi_kernel, s_valid=s, bs=bs,
                           dt=dt),

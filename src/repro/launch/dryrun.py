@@ -25,7 +25,6 @@ import jax
 
 from ..configs import ASSIGNED_NAMES, SHAPES, get_config, shape_supported
 from ..distributed.api import use_mesh
-from ..distributed.compat import cost_analysis_dict
 from ..distributed.sharding import ShardingOptions
 from ..roofline.analysis import (
     HBM_BW,
@@ -72,7 +71,7 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool = False,
         t_compile = time.time() - t0 - t_lower
 
     mem = compiled.memory_analysis()
-    ca = cost_analysis_dict(compiled)
+    ca = compiled.cost_analysis()
     hlo_text = compiled.as_text()
     mc = parse_module_collectives(
         hlo_text, pod_size=256 if multi_pod else None

@@ -373,7 +373,7 @@ def gqa_apply(params, cfg, x, *, layer_is_global: bool = True,
         # (write-then-read keeps chunked prefill self-attending, exactly
         # like the ring path below), then attend the pool — in place via
         # the Pallas kernel on the decode hot path, or through the
-        # gathered row view (the bit-exact oracle / S>1 fallback). The
+        # gathered row view (the kernel's oracle / S>1 fallback). The
         # speculative-decoding verify step (serve/spec_decode.py) is an
         # S = k+1 decode continuation and deliberately takes the gather
         # route: every lane needs its own causal slice of the pool, which

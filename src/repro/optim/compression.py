@@ -20,7 +20,6 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from ..distributed.compat import shard_map
 
 
 def quantize_int8(x):
@@ -74,7 +73,7 @@ def pod_allreduce_compressed(grads, err_state, mesh, specs):
     """
 
     @partial(
-        shard_map, mesh=mesh, in_specs=(specs, specs),
+        jax.shard_map, mesh=mesh, in_specs=(specs, specs),
         out_specs=(specs, specs),
     )
     def run(g, e):
@@ -93,7 +92,7 @@ def pod_allreduce_mean(grads, mesh, specs):
     """Exact (uncompressed) pod mean-reduce, same shard_map structure —
     the baseline the compression is measured against."""
 
-    @partial(shard_map, mesh=mesh, in_specs=(specs,), out_specs=specs)
+    @partial(jax.shard_map, mesh=mesh, in_specs=(specs,), out_specs=specs)
     def run(g):
         return jax.tree_util.tree_map(lambda x: jax.lax.pmean(x, "pod"), g)
 

@@ -22,6 +22,7 @@ resident — the high-water mark is tracked and reported per engine.
 """
 from __future__ import annotations
 
+import logging
 from typing import List, Optional, Sequence, Tuple
 
 import jax
@@ -32,6 +33,8 @@ from ..layers.attention import init_paged_kv_cache
 from ..layers.ssm import init_ssm_cache
 from .cache_pool import CacheBackend
 from .prefix_cache import RadixPrefixCache
+
+log = logging.getLogger(__name__)
 
 # Fixed width of the jitted block clear/copy programs: ids are padded to a
 # multiple of this with out-of-range sentinels (dropped scatters), so any
@@ -197,12 +200,13 @@ class PagedBackend(CacheBackend):
 
         # Decode runs the Pallas paged-attention kernel by default (tiles
         # streamed from the pool in place); use_kernel=False keeps the jnp
-        # row-view gather — the bit-exact oracle the kernel is tested
-        # against. Chunked prefill always takes the gather path (S > 1).
+        # row-view gather — the oracle the kernel is tested against (same
+        # math, another f32 summation order). Chunked prefill always takes
+        # the gather path (S > 1).
         # A kernel failure at dispatch degrades PERMANENTLY to the gather
-        # oracle (`_kernel_fallback`) instead of taking serving down;
-        # `kernel_fallbacks` counts the degradations for the metrics
-        # surface.
+        # oracle (`_kernel_fallback`) instead of taking serving down; the
+        # exception is logged and `kernel_fallbacks` counts the
+        # degradations for the metrics surface.
         self.use_kernel = use_kernel
         self.kernel_fallbacks = 0
         # Telemetry variants of the programs (see serve/programs.py):
@@ -376,18 +380,25 @@ class PagedBackend(CacheBackend):
             self._touch_live_hw()  # divergence: one more unique block
         return True
 
-    def _kernel_fallback(self):
+    def _kernel_fallback(self, err: Exception):
         """Graceful degradation: a Pallas kernel failure (compile or
         dispatch) rebuilds BOTH multi-token programs on the jnp gather
         oracle and turns the kernel off for the backend's lifetime. The
-        gather path is bit-exact, so serving continues unchanged — only
-        the decode HBM saving is lost. Safe to invoke at trace/compile
+        gather path computes the same attention in another f32 summation
+        order, so serving continues (greedy streams can part only where
+        two tokens tie within bf16 rounding) — the decode HBM saving is
+        lost. Safe to invoke at trace/compile
         failure time: the cache pytree is only replaced on a successful
         call, and buffer donation cannot have consumed it before the
-        program ever ran."""
+        program ever ran. ``err`` is logged with its traceback: a kernel
+        the device's compiler refuses must not pass silently as a
+        slower gather path."""
         from .programs import make_decode_step_paged, make_verify_step_paged
 
         assert self.use_kernel, "fallback with the kernel already off"
+        log.error("paged-attention kernel program failed; falling back to "
+                  "the jnp gather path: %s: %s", type(err).__name__, err,
+                  exc_info=err)
         self.use_kernel = False
         self.kernel_fallbacks += 1
         # the rebuilt programs must keep the telemetry flag: losing it
@@ -410,10 +421,10 @@ class PagedBackend(CacheBackend):
             out = self._decode(
                 params, toks, pos, self._tables_dev, self.cache
             )
-        except Exception:
+        except Exception as err:
             if not self.use_kernel:
                 raise
-            self._kernel_fallback()
+            self._kernel_fallback(err)
             out = self._decode(
                 params, toks, pos, self._tables_dev, self.cache
             )
@@ -429,10 +440,10 @@ class PagedBackend(CacheBackend):
             out = self._verify(
                 params, toks, poss, self._tables_dev, self.cache
             )
-        except Exception:
+        except Exception as err:
             if not self.use_kernel:
                 raise
-            self._kernel_fallback()
+            self._kernel_fallback(err)
             out = self._verify(
                 params, toks, poss, self._tables_dev, self.cache
             )

@@ -223,7 +223,7 @@ def make_decode_step_paged(cfg, use_kernel: bool = False,
     streams pool tiles in place — no per-step (B, blocks_per_row *
     block_size, ...) row-view gather in the decode jaxpr (proved by
     ``benchmarks.bench_kernels.check_paged_materialization``). The
-    default jnp gather path is the bit-exact oracle."""
+    default jnp gather path is the oracle it is tested against."""
 
     def decode(params, tokens, pos, tables, cache):
         out = lm_apply(

@@ -6,7 +6,6 @@ import numpy as np
 import pytest
 
 from repro.configs import SHAPES, get_config
-from repro.distributed.compat import cost_analysis_dict
 from repro.roofline.analysis import model_flops
 from repro.roofline.flops import analytic_cost, fwd_flops
 from repro.roofline.hlo_parse import (
@@ -99,8 +98,7 @@ def test_analytic_flops_cross_check_vs_hlo():
                         cache=cache, mode="decode")[0]
 
     c = jax.jit(decode).lower(params, toks, cache).compile()
-    # cost_analysis() returns a list-of-dicts on jax 0.4.x — normalize
-    hlo_flops = cost_analysis_dict(c).get("flops", 0)
+    hlo_flops = c.cost_analysis().get("flops", 0)
     ana = fwd_flops(cfg, B, 1, "decode", cache_len=S)
     assert ana > 0 and hlo_flops > 0
     ratio = ana / hlo_flops

@@ -109,9 +109,10 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
+from repro.launch.mesh import make_mesh
 from repro.optim import ef_state_init, pod_allreduce_compressed, pod_allreduce_mean
 
-mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
 g = {"w": jax.random.normal(jax.random.PRNGKey(0), (8, 16))}
 # different gradient per pod: shard over pod so each pod holds its own
 specs = {"w": P("pod", None)}
