@@ -86,50 +86,54 @@ def soft_moe_apply(params, moe_cfg, x, act: str = "silu",
     n, p = moe_cfg.num_experts, moe_cfg.slots_per_expert
     phi = params["phi"]
     c_weights = c_stats = d_w = d_stats = None
-    if use_kernel:
-        from ..kernels import ops as kops
-        from ..kernels.tuning import config_from_moe
+    with jax.named_scope("dispatch"):
+        if use_kernel:
+            from ..kernels import ops as kops
+            from ..kernels.tuning import config_from_moe
 
-        kcfg = config_from_moe(moe_cfg, m=m, d=d)
-        phi_n = kops.normalized_phi(phi, params["scale"])
-        # one logits pass: dispatched slots + the combine softmax stats
-        if telemetry:
-            slots, d_stats, c_stats = kops.soft_moe_routing(
-                x, phi_n, config=kcfg, with_d_stats=True)
+            kcfg = config_from_moe(moe_cfg, m=m, d=d)
+            phi_n = kops.normalized_phi(phi, params["scale"])
+            # one logits pass: dispatched slots + the combine softmax stats
+            if telemetry:
+                slots, d_stats, c_stats = kops.soft_moe_routing(
+                    x, phi_n, config=kcfg, with_d_stats=True)
+            else:
+                slots, c_stats = kops.soft_moe_routing(x, phi_n, config=kcfg)
+            slots = slots.reshape(b, n, p, d)  # (b, n·p, d) -> (b, n, p, d)
         else:
-            slots, c_stats = kops.soft_moe_routing(x, phi_n, config=kcfg)
-        slots = slots.reshape(b, n, p, d)  # (b, n·p, d) -> (b, n, p, d)
-    else:
-        d_w, c_weights = soft_moe_weights(x, phi, params["scale"])
-        # Distribution note: GSPMD's propagated layout (slot axis of the
-        # weight tensors sharded with Phi over `model`) is left alone.
-        # Forcing slot-replication here (gather the small axis early,
-        # avoid the combine all-reduce) was tried and REFUTED — it ADDED
-        # ~1.3s/step of resharding traffic at deepseek+soft:train_4k
-        # (EXPERIMENTS.md §Perf, H7).
-        # input slots: weighted average of all tokens per slot
-        slots = jnp.einsum("bmd,bmnp->bnpd", x.astype(jnp.float32), d_w)
-    slots = slots.astype(x.dtype)
+            d_w, c_weights = soft_moe_weights(x, phi, params["scale"])
+            # Distribution note: GSPMD's propagated layout (slot axis of the
+            # weight tensors sharded with Phi over `model`) is left alone.
+            # Forcing slot-replication here (gather the small axis early,
+            # avoid the combine all-reduce) was tried and REFUTED — it ADDED
+            # ~1.3s/step of resharding traffic at deepseek+soft:train_4k
+            # (EXPERIMENTS.md §Perf, H7).
+            # input slots: weighted average of all tokens per slot
+            slots = jnp.einsum("bmd,bmnp->bnpd", x.astype(jnp.float32), d_w)
+        slots = slots.astype(x.dtype)
 
     # expert compute: (b,n,p,d) -> (n, b*p, d) so the expert axis leads
     # (sharded over `model` = expert parallelism)
-    ys = slots.transpose(1, 0, 2, 3).reshape(n, b * p, d)
-    ys = experts_apply(params["experts"], ys, act)
-    ys = ys.reshape(n, b, p, d).transpose(1, 0, 2, 3)  # (b,n,p,d)
+    with jax.named_scope("experts"):
+        ys = slots.transpose(1, 0, 2, 3).reshape(n, b * p, d)
+        ys = experts_apply(params["experts"], ys, act)
+        ys = ys.reshape(n, b, p, d).transpose(1, 0, 2, 3)  # (b,n,p,d)
 
-    if use_kernel:
-        y = kops.soft_moe_combine(x, phi_n, ys.reshape(b, n * p, d),
-                                  c_stats=c_stats, config=kcfg)
-    else:
-        y = jnp.einsum(
-            "bnpd,bmnp->bmd", ys.astype(jnp.float32), c_weights
-        )
-    y = y.astype(x.dtype)
+    with jax.named_scope("combine"):
+        if use_kernel:
+            y = kops.soft_moe_combine(x, phi_n, ys.reshape(b, n * p, d),
+                                      c_stats=c_stats, config=kcfg)
+        else:
+            y = jnp.einsum(
+                "bnpd,bmnp->bmd", ys.astype(jnp.float32), c_weights
+            )
+        y = y.astype(x.dtype)
 
     if moe_cfg.num_shared_experts:
         # reshape once; experts_apply broadcasts the leading expert axis
         # (no (num_shared × b·m × d) materialization).
-        sh = experts_apply(params["shared"], x.reshape(1, b * m, d), act)
+        with jax.named_scope("experts"):
+            sh = experts_apply(params["shared"], x.reshape(1, b * m, d), act)
         y = y + sh.sum(0).reshape(b, m, d)
 
     metrics = {

@@ -186,5 +186,6 @@ def paged_decode_attend(q, k_pool, v_pool, pos_pool, tables, q_pos, *,
         ),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, dv), q.dtype),
+        name="paged_decode_attend",
         interpret=cfg.resolve_interpret(),
     )(tables, q_pos, q, k_pool, v_pool, pos_pool[:, None, :])

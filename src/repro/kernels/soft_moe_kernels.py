@@ -253,6 +253,7 @@ def routing_fwd_pallas(x, phi_n, cfg: KernelConfig = KernelConfig()):
             pltpu.VMEM((m_pad // bt, 1, bt), dt),  # combine running max
             pltpu.VMEM((m_pad // bt, 1, bt), dt),  # combine running denom
         ],
+        name="softmoe_routing_fwd",
         interpret=cfg.resolve_interpret(),
     )(x, phi_n)
     return (slots[:, :s], (dmx[:, 0, :s], dden[:, 0, :s]),
@@ -376,6 +377,7 @@ def routing_health_pallas(x, phi_n, d_stats, c_stats,
             pltpu.VMEM((m_pad // bt, 1, bt), dt),  # combine entropy
             pltpu.VMEM((m_pad // bt, 1, bt), dt),  # token contribution
         ],
+        name="softmoe_routing_health",
         interpret=cfg.resolve_interpret(),
     )(x, phi_n, dmx, dden, cmx, cden)
     return (dent[:, 0, :s], imp[:, 0, :s], cent[:, 0, :m],
@@ -464,6 +466,7 @@ def _combine_call(x, phi_n, ys, c_stats, cfg: KernelConfig):
                 pltpu.VMEM((1, bt), dt),
                 pltpu.VMEM((1, bt), dt),
             ],
+            name="softmoe_combine_online",
             interpret=cfg.resolve_interpret(),
         )(x, phi_n, ys)
         y, cmx, cden = out
@@ -479,6 +482,7 @@ def _combine_call(x, phi_n, ys, c_stats, cfg: KernelConfig):
         out_specs=y_spec,
         out_shape=y_shape,
         scratch_shapes=[pltpu.VMEM((bt, d), dt)],
+        name="softmoe_combine_fwd",
         interpret=cfg.resolve_interpret(),
     )(x, phi_n, ys, cmx, cden)
     return y[:, :m], c_stats
@@ -596,6 +600,7 @@ def dispatch_bwd_pallas(x, phi_n, g_slots, d_stats, slots,
         out_specs=x_spec_t,
         out_shape=jax.ShapeDtypeStruct((b, m_pad, d), x.dtype),
         scratch_shapes=[pltpu.VMEM((bt, d), dt), pltpu.VMEM((bt, d), dt)],
+        name="softmoe_dispatch_bwd_dx",
         interpret=cfg.resolve_interpret(),
     )(*args)
 
@@ -612,6 +617,7 @@ def dispatch_bwd_pallas(x, phi_n, g_slots, d_stats, slots,
         out_specs=pl.BlockSpec((d, bs), lambda js, jb, jt: (0, js)),
         out_shape=jax.ShapeDtypeStruct((d, s_pad), phi_n.dtype),
         scratch_shapes=[pltpu.VMEM((d, bs), dt)],
+        name="softmoe_dispatch_bwd_dphi",
         interpret=cfg.resolve_interpret(),
     )(*args)
     return dx[:, :m], dphi[:, :s]
@@ -725,6 +731,7 @@ def combine_bwd_pallas(x, phi_n, ys, g, c_stats, y,
         out_specs=x_spec_t,
         out_shape=jax.ShapeDtypeStruct((b, m_pad, d), x.dtype),
         scratch_shapes=[pltpu.VMEM((bt, d), dt)],
+        name="softmoe_combine_bwd_dx",
         interpret=cfg.resolve_interpret(),
     )(*args)
 
@@ -749,6 +756,7 @@ def combine_bwd_pallas(x, phi_n, ys, g, c_stats, y,
             jax.ShapeDtypeStruct((d, s_pad), phi_n.dtype),
         ),
         scratch_shapes=[pltpu.VMEM((bs, d), dt), pltpu.VMEM((d, bs), dt)],
+        name="softmoe_combine_bwd_dys_dphi",
         interpret=cfg.resolve_interpret(),
     )(*args)
     return dx[:, :m], dphi[:, :s], dys[:, :s]

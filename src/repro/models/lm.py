@@ -84,26 +84,29 @@ def block_apply(params, cfg, x, *, is_moe: bool, is_global=True,
     the block's output is bit-identical either way."""
     aux = jnp.zeros((), jnp.float32)
     new_cache = {} if cache is not None else None
-    xn = norm_apply(params["norm1"], cfg, x)
+    with jax.named_scope("norm"):
+        xn = norm_apply(params["norm1"], cfg, x)
     mix = 0.0
     a_out = None
     if cfg.has_attention():
-        a_out, a_cache = attention_apply(
-            params["attn"], cfg, xn,
-            layer_is_global=is_global, positions=positions,
-            cache=None if cache is None else cache.get("attn"),
-            mode=mode, block_tables=block_tables,
-            paged_kernel=paged_kernel,
-        )
+        with jax.named_scope("attention"):
+            a_out, a_cache = attention_apply(
+                params["attn"], cfg, xn,
+                layer_is_global=is_global, positions=positions,
+                cache=None if cache is None else cache.get("attn"),
+                mode=mode, block_tables=block_tables,
+                paged_kernel=paged_kernel,
+            )
         mix = mix + a_out
         if new_cache is not None:
             new_cache["attn"] = a_cache
     if cfg.has_ssm():
-        s_out, s_cache = ssm_apply(
-            params["ssm"], cfg, xn,
-            cache=None if cache is None else cache.get("ssm"), mode=mode,
-            positions=positions,
-        )
+        with jax.named_scope("ssm"):
+            s_out, s_cache = ssm_apply(
+                params["ssm"], cfg, xn,
+                cache=None if cache is None else cache.get("ssm"),
+                mode=mode, positions=positions,
+            )
         if cfg.hybrid_parallel and cfg.has_attention():
             mix = (mix + s_out) * 0.5  # Hymba: mean-fuse parallel heads
         else:
@@ -114,17 +117,20 @@ def block_apply(params, cfg, x, *, is_moe: bool, is_global=True,
 
     moe_telem = None
     if "norm2" in params:
-        xn = norm_apply(params["norm2"], cfg, x)
+        with jax.named_scope("norm"):
+            xn = norm_apply(params["norm2"], cfg, x)
         if is_moe:
-            m_out, metrics = moe_apply(
-                params["moe"], resolve_moe_cfg(cfg.moe, cfg.d_ff), xn,
-                cfg.act, use_kernel=use_kernel, telemetry=telemetry,
-                mode=mode,
-            )
+            with jax.named_scope("moe"):
+                m_out, metrics = moe_apply(
+                    params["moe"], resolve_moe_cfg(cfg.moe, cfg.d_ff), xn,
+                    cfg.act, use_kernel=use_kernel, telemetry=telemetry,
+                    mode=mode,
+                )
             aux = aux + metrics["moe_aux_loss"]
             moe_telem = metrics.get("telemetry")
         else:
-            m_out = mlp_apply(params["mlp"], xn, cfg.act)
+            with jax.named_scope("mlp"):
+                m_out = mlp_apply(params["mlp"], xn, cfg.act)
         x = x + constrain(m_out, "batch", "seq", None)
     if not telemetry:
         return x, new_cache, aux

@@ -51,7 +51,8 @@ def adamw_update(grads, opt_state, params, cfg: OptimizerConfig,
                  lr=None):
     step = opt_state["step"] + 1
     lr = schedule_lr(cfg, step) if lr is None else lr
-    grads, gnorm = clip_by_global_norm(grads, cfg.grad_clip_norm)
+    with jax.named_scope("clip"):
+        grads, gnorm = clip_by_global_norm(grads, cfg.grad_clip_norm)
     b1, b2 = cfg.b1, cfg.b2
 
     def upd(g, m, v, p):
@@ -67,7 +68,9 @@ def adamw_update(grads, opt_state, params, cfg: OptimizerConfig,
     flat_g = treedef.flatten_up_to(grads)
     flat_m = treedef.flatten_up_to(opt_state["mu"])
     flat_v = treedef.flatten_up_to(opt_state["nu"])
-    out = [upd(g, m, v, p) for g, m, v, p in zip(flat_g, flat_m, flat_v, flat_p)]
+    with jax.named_scope("adamw"):
+        out = [upd(g, m, v, p)
+               for g, m, v, p in zip(flat_g, flat_m, flat_v, flat_p)]
     new_p = treedef.unflatten([o[0] for o in out])
     new_m = treedef.unflatten([o[1] for o in out])
     new_v = treedef.unflatten([o[2] for o in out])

@@ -38,6 +38,8 @@ import time
 from collections import deque
 from typing import Dict, List, Optional, Sequence
 
+import jax
+
 # -- span kinds --------------------------------------------------------------
 
 SPAN_SUBMITTED = "submitted"
@@ -294,7 +296,12 @@ class ProgramTimer:
 
     ``calls``/``total_s`` accumulate for the wrapper's lifetime;
     ``take_tick()`` drains the per-tick delta the flight recorder
-    stores. Unknown attributes (``_cache_size``, ...) pass through to
+    stores. ``total_s`` times the host call: the dispatch plus whatever
+    wait happens inside it (a device-to-host read in the wrapped
+    function, a full dispatch queue), not the program's device time.
+    Each call is also a profiler span named ``name``
+    (``jax.profiler.TraceAnnotation``), on the clock of the device
+    trace. Unknown attributes (``_cache_size``, ...) pass through to
     the wrapped function, so jit-cache introspection is unchanged."""
 
     def __init__(self, name: str, fn):
@@ -306,9 +313,10 @@ class ProgramTimer:
         self._tick_s = 0.0
 
     def __call__(self, *args, **kwargs):
-        t0 = time.perf_counter()
-        out = self.fn(*args, **kwargs)
-        dt = time.perf_counter() - t0
+        with jax.profiler.TraceAnnotation(self.name):
+            t0 = time.perf_counter()
+            out = self.fn(*args, **kwargs)
+            dt = time.perf_counter() - t0
         self.calls += 1
         self.total_s += dt
         self._tick_calls += 1

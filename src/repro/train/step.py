@@ -95,25 +95,28 @@ def make_train_step(loss_fn: Callable, opt_cfg: OptimizerConfig,
             loss, metrics, grads = accumulate(state["params"], batch)
         else:
             loss, metrics, grads = grads_of(state["params"], batch)
-        if "master" in state:  # ZeRO-1: update the sharded fp32 master,
-            # then re-broadcast bf16 compute params. The grads->master
-            # resharding lowers to a reduce-scatter; the cast-back to the
-            # replicated layout lowers to one all-gather per step (vs one
-            # per layer per pass under FSDP).
-            grads = _match_sharding(grads, state["master"])
-            new_master, new_opt, opt_metrics = adamw_update(
-                grads, state["opt"], state["master"], opt_cfg
-            )
-            new_params = jax.tree_util.tree_map(
-                lambda m, p: m.astype(p.dtype), new_master, state["params"]
-            )
-            new_state = {"params": new_params, "master": new_master,
-                         "opt": new_opt}
-        else:
-            new_params, new_opt, opt_metrics = adamw_update(
-                grads, state["opt"], state["params"], opt_cfg
-            )
-            new_state = {"params": new_params, "opt": new_opt}
+        with jax.named_scope("optimizer"):
+            if "master" in state:  # ZeRO-1: update the sharded fp32
+                # master, then re-broadcast bf16 compute params. The
+                # grads->master resharding lowers to a reduce-scatter; the
+                # cast-back to the replicated layout lowers to one
+                # all-gather per step (vs one per layer per pass under
+                # FSDP).
+                grads = _match_sharding(grads, state["master"])
+                new_master, new_opt, opt_metrics = adamw_update(
+                    grads, state["opt"], state["master"], opt_cfg
+                )
+                new_params = jax.tree_util.tree_map(
+                    lambda m, p: m.astype(p.dtype), new_master,
+                    state["params"]
+                )
+                new_state = {"params": new_params, "master": new_master,
+                             "opt": new_opt}
+            else:
+                new_params, new_opt, opt_metrics = adamw_update(
+                    grads, state["opt"], state["params"], opt_cfg
+                )
+                new_state = {"params": new_params, "opt": new_opt}
         metrics = dict(metrics)
         metrics.update(opt_metrics)
         metrics["total_loss"] = loss

@@ -8,7 +8,10 @@
   * straggler watchdog — per-step wall time EWMA; steps slower than
     ``straggler_factor``× the EWMA are logged with their step index (on a
     real fleet this feeds the scheduler to replace the slow host; here it
-    records the event and optionally aborts-to-restart).
+    records the event and optionally aborts-to-restart). Each step is a
+    profiler span, ``StepTraceAnnotation("train_step", step_num=step)``,
+    holding the spans ``data``, ``dispatch``, ``sync`` and ``checkpoint``,
+    so a logged straggler is found in a profile by its step number.
   * elastic scaling — restore() re-shards onto whatever mesh the restarted
     job has (see CheckpointManager.restore); nothing in the loop assumes
     the device count of the previous incarnation.
@@ -21,6 +24,7 @@ from dataclasses import dataclass, field
 from typing import Any, Callable, List, Optional
 
 import jax
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 from ..checkpoint.manager import CheckpointManager
 from ..optim import OptimizerConfig
@@ -109,47 +113,60 @@ class Trainer:
 
         step = start_step
         while step < self.cfg.total_steps:
-            batch = self.data.batch(step)
-            t0 = time.perf_counter()
-            state, metrics = self.train_step(state, batch)
-            jax.block_until_ready(metrics["total_loss"])
-            dt = time.perf_counter() - t0
-            slow = self.watchdog.observe(step, dt)
-            if slow:
-                print(f"[watchdog] straggler step {step}: {dt:.3f}s "
-                      f"(ewma {self.watchdog.ewma:.3f}s)")
-                if self.cfg.straggler_abort:
-                    self.ckpt.save(step, state, blocking=True)
-                    raise RuntimeError("straggler abort -> restart")
-            step += 1
-            if step % self.cfg.log_every == 0:
-                # A telemetry-enabled loss_fn (lm_loss(telemetry=True))
-                # nests the model-interior stats pytree under
-                # metrics["telemetry"]; flatten it to scalars next to the
-                # scalar metrics (serve/telemetry.py owns the naming).
-                telem = metrics.pop("telemetry", None)
-                m = {k: float(v) for k, v in metrics.items()}
-                if telem is not None:
-                    from ..serve.telemetry import flatten_telemetry
-                    m.update({
-                        f"telemetry_{k}": v for k, v in
-                        flatten_telemetry(jax.device_get(telem)).items()
-                    })
-                m["step"] = step
-                m["step_time"] = dt
-                self.metrics_history.append(m)
-                print(f"[trainer] step {step}: loss={m.get('total_loss'):.4f}"
-                      f" lr={m.get('lr', 0):.2e} dt={dt:.3f}s")
-            if step % self.cfg.checkpoint_every == 0:
-                self.ckpt.save_async(step, state)
+            with StepTraceAnnotation("train_step", step_num=step):
+                with TraceAnnotation("data"):
+                    batch = self.data.batch(step)
+                t0 = time.perf_counter()
+                with TraceAnnotation("dispatch"):
+                    state, metrics = self.train_step(state, batch)
+                with TraceAnnotation("sync"):
+                    jax.block_until_ready(metrics["total_loss"])
+                dt = time.perf_counter() - t0
+                slow = self.watchdog.observe(step, dt)
+                if slow:
+                    print(f"[watchdog] straggler step {step}: {dt:.3f}s "
+                          f"(ewma {self.watchdog.ewma:.3f}s)")
+                    if self.cfg.straggler_abort:
+                        with TraceAnnotation("checkpoint"):
+                            self.ckpt.save(step, state, blocking=True)
+                        raise RuntimeError("straggler abort -> restart")
+                step += 1
+                if step % self.cfg.log_every == 0:
+                    self._log(step, metrics, dt)
+                if step % self.cfg.checkpoint_every == 0:
+                    with TraceAnnotation("checkpoint"):
+                        self.ckpt.save_async(step, state)
             if self._preempted:
                 print(f"[trainer] preempted at step {step}; checkpointing")
-                self.ckpt.wait()
-                self.ckpt.save(step, state, blocking=True)
+                self._save(step, state)
                 return state
-        self.ckpt.wait()
-        self.ckpt.save(step, state, blocking=True)
+        self._save(step, state)
         return state
+
+    def _save(self, step: int, state):
+        """Blocking save after any async one in flight."""
+        with TraceAnnotation("checkpoint"):
+            self.ckpt.wait()
+            self.ckpt.save(step, state, blocking=True)
+
+    def _log(self, step: int, metrics: dict, dt: float):
+        # A telemetry-enabled loss_fn (lm_loss(telemetry=True)) nests the
+        # model-interior stats pytree under metrics["telemetry"]; flatten
+        # it to scalars next to the scalar metrics (serve/telemetry.py
+        # owns the naming).
+        telem = metrics.pop("telemetry", None)
+        m = {k: float(v) for k, v in metrics.items()}
+        if telem is not None:
+            from ..serve.telemetry import flatten_telemetry
+            m.update({
+                f"telemetry_{k}": v for k, v in
+                flatten_telemetry(jax.device_get(telem)).items()
+            })
+        m["step"] = step
+        m["step_time"] = dt
+        self.metrics_history.append(m)
+        print(f"[trainer] step {step}: loss={m.get('total_loss'):.4f}"
+              f" lr={m.get('lr', 0):.2e} dt={dt:.3f}s")
 
     def _abstract_state(self, rng):
         return jax.eval_shape(

@@ -133,3 +133,34 @@ def test_paper_vit_models_train():
     batch = _batch_for(cfg, jax.random.PRNGKey(1))
     l, metrics = loss_fn(params, batch)
     assert bool(jnp.isfinite(l))
+
+
+def test_vit_features_are_the_pooled_encoder_of_vit_apply():
+    """``vit_features`` is the mean pool of ``vit_apply``'s encoder output
+    after ``final_norm``: the head applied to it gives ``vit_apply``'s
+    logits, and it equals the embedding, block loop and final norm
+    written out."""
+    from repro.layers.common import norm_apply
+    from repro.models.lm import block_apply, segment_plan
+    from repro.models.vit import vit_apply, vit_features, vit_init
+
+    cfg = reduced(get_config("soft-moe-s/16-128e"))
+    params = vit_init(jax.random.PRNGKey(0), cfg, num_classes=10)
+    head = params["head"]
+    head["w"] = jax.random.normal(jax.random.PRNGKey(2), head["w"].shape)
+    patches = _batch_for(cfg, jax.random.PRNGKey(1))["patches"]
+    feats = vit_features(params, cfg, patches)
+    logits, _ = vit_apply(params, cfg, patches)
+    assert jnp.allclose(feats.astype(jnp.float32) @ head["w"] + head["b"],
+                        logits, rtol=1e-5, atol=1e-5)
+
+    dt = jnp.dtype(cfg.dtype)
+    x = patches.astype(dt) @ params["patch_proj"]["w"].astype(dt)
+    x = x + params["patch_proj"]["b"].astype(dt) + params["pos_emb"].astype(dt)
+    for seg, (_, _, is_moe) in zip(params["segments"], segment_plan(cfg)):
+        x, _ = jax.lax.scan(
+            lambda c, p, _m=is_moe: (block_apply(p, cfg, c, is_moe=_m)[0],
+                                     None), x, seg)
+    want = norm_apply(params["final_norm"], cfg, x).mean(axis=1)
+    assert feats.dtype == want.dtype
+    assert jnp.allclose(feats, want, rtol=1e-6, atol=1e-6)

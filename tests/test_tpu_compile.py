@@ -4,13 +4,17 @@ Interpret mode, in which every other kernel test runs on the CPU, never
 applies Mosaic's tiling rules; the TPU compiler, installed here without a
 chip, does. Each test lowers kernels at published widths for one chip of a
 described v5e:2x2 topology and asserts that the compiled HLO holds them
-(``tpu_custom_call``). Nothing runs, so nothing here says anything about
-results or time.
+(``tpu_custom_call``) under their stable names, which the device trace
+shows as the instruction name of each kernel's op. Nothing runs, so
+nothing here says anything about results or time.
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and every test worker imports
 this file.
 """
+import collections
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -19,6 +23,7 @@ from jax.sharding import SingleDeviceSharding
 from repro.configs import get_config
 from repro.kernels import KernelConfig, default_config, ops, paged_config
 from repro.kernels.paged_attention_kernels import paged_decode_attend
+from repro.launch.scope_time import KERNELS
 
 # soft-moe-s/16-128e: tokens per image, width, slots
 _VIT = get_config("soft-moe-s/16-128e")
@@ -59,6 +64,20 @@ def _kernel_hlo(fn, *args) -> str:
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
+_PALLAS_OP = re.compile(
+    r'^\s*(?:ROOT )?%(\S+) = .*custom_call_target="tpu_custom_call"', re.M)
+
+
+def _kernel_names(hlo: str) -> collections.Counter:
+    """How many Pallas ops of each kernel name the compiled HLO holds, by
+    the name each op's instruction name contains (a transform may wrap
+    it: ``transpose_jvp_softmoe_combine_bwd_dx__.3``); ``None`` counts
+    the ops that carry none of them."""
+    return collections.Counter(
+        next((k for k in KERNELS if k in op), None)
+        for op in _PALLAS_OP.findall(hlo))
+
+
 def _routing_combine(x, phi_n, ys):
     cfg = default_config(M, D, S, KernelConfig(interpret=False))
     slots, d_stats, c_stats = ops.soft_moe_routing(x, phi_n, cfg,
@@ -87,6 +106,9 @@ def test_soft_moe_forward_kernels_compile(one_chip, b):
     hlo = _kernel_hlo(_soft_moe_fwd, *args)
     # routing, combine (saved stats), combine (online), routing health
     assert hlo.count('custom_call_target="tpu_custom_call"') == 4
+    assert _kernel_names(hlo) == {
+        "softmoe_routing_fwd": 1, "softmoe_combine_fwd": 1,
+        "softmoe_combine_online": 1, "softmoe_routing_health": 1}
 
 
 @pytest.mark.parametrize("b", [8, 1])
@@ -96,6 +118,11 @@ def test_soft_moe_backward_kernels_compile(one_chip, b):
     hlo = _kernel_hlo(jax.grad(_soft_moe_loss, argnums=(0, 1, 2)), *args)
     # 3 forward + dispatch dx/dphi + 2 x combine dx/(dys, dphi)
     assert hlo.count('custom_call_target="tpu_custom_call"') == 9
+    assert _kernel_names(hlo) == {
+        "softmoe_routing_fwd": 1, "softmoe_combine_fwd": 1,
+        "softmoe_combine_online": 1, "softmoe_dispatch_bwd_dx": 1,
+        "softmoe_dispatch_bwd_dphi": 1, "softmoe_combine_bwd_dx": 2,
+        "softmoe_combine_bwd_dys_dphi": 2}
 
 
 def test_paged_decode_kernel_compiles(one_chip):
@@ -112,4 +139,4 @@ def test_paged_decode_kernel_compiles(one_chip):
     )
     cfg = paged_config(BLOCK, KernelConfig(interpret=False))
     hlo = _kernel_hlo(lambda *a: paged_decode_attend(*a, cfg=cfg), *args)
-    assert 'custom_call_target="tpu_custom_call"' in hlo
+    assert _kernel_names(hlo) == {"paged_decode_attend": 1}
