@@ -1,7 +1,8 @@
 """Pallas TPU kernels for the Soft-MoE hot path (dispatch/combine, fused
 forward AND flash-style backward, with pure-jnp oracles in ref.py — see
-soft_moe_kernels.py) and for paged decode attention over the serving
-block pool (paged_attention_kernels.py); tuning.py holds block-size /
+soft_moe_kernels.py), for paged decode attention over the serving
+block pool (paged_attention_kernels.py) and for full-sequence encoder
+attention (attention_kernels.py); tuning.py holds block-size /
 interpret policy for all of them."""
 from . import ops, ref, tuning  # noqa: F401
 from .paged_attention_kernels import paged_decode_attend  # noqa: F401

@@ -44,7 +44,7 @@ KERNELS = ("softmoe_routing_fwd", "softmoe_routing_health",
            "softmoe_combine_online", "softmoe_combine_fwd",
            "softmoe_dispatch_bwd_dx", "softmoe_dispatch_bwd_dphi",
            "softmoe_combine_bwd_dx", "softmoe_combine_bwd_dys_dphi",
-           "paged_decode_attend")
+           "paged_decode_attend", "mha_full_fwd")
 
 _MODULE = re.compile(r"^HloModule ([^\s,]+)", re.M)
 _COMPUTATION = re.compile(r"^(?:ENTRY )?%([\w.\-]+) ")

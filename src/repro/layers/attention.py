@@ -9,14 +9,16 @@ Three execution modes share one code path:
 """
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
+from ..kernels import attention_kernels
 from ..kernels.paged_attention_kernels import paged_decode_attend
 from .common import lecun_init, split_rngs
-from .rotary import apply_rope
+from .rotary import apply_rope, rope_cos_sin
 
 NEG_INF = -2.0**30
 
@@ -336,9 +338,64 @@ def reset_kv_rows(cache, row):
     return dict(cache, pos=cache["pos"].at[row].set(-1))
 
 
+def _project_qkv(params, x, positions, theta: float):
+    """q and k (roped at ``positions``) and v, each (b, s, heads,
+    head_dim); the biases join when ``params`` holds them."""
+    dt = x.dtype
+    out = []
+    for n in ("q", "k", "v"):
+        y = jnp.einsum("bsd,dhk->bshk", x, params["w" + n].astype(dt))
+        if "b" + n in params:
+            y = y + params["b" + n].astype(dt)
+        out.append(y if n == "v" else apply_rope(y, positions, theta))
+    return out
+
+
+def _attend_full_plain(params, x, theta: float):
+    """What ``_attend_full`` computes, by the projections, ``apply_rope``
+    and ``_attend``: the path a differentiated call takes."""
+    return _attend(*_project_qkv(params, x, jnp.arange(x.shape[1]), theta),
+                   None)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
+def _attend_full(params, x, num_heads: int, theta: float):
+    """Unmasked self-attention of ``x`` (b, s, d) with the q/k/v weights
+    (and biases) in ``params``: (b, s, heads, head_dim). One projection
+    gives q, k and v side by side, which the fused kernel
+    (kernels/attention_kernels.py) reads as they are and ropes itself:
+    neither the logits nor a roped or transposed copy of q and k reaches
+    HBM. Differentiated, it runs and differentiates ``_attend_full_plain``
+    instead: at train batches the kernel is no faster than XLA's
+    attention, and a backward from the kernel's outputs would recompute
+    the logits once more."""
+    dt = x.dtype
+    names = ("q", "k", "v")
+    w = jnp.concatenate([params["w" + n].astype(dt).reshape(x.shape[-1], -1)
+                         for n in names], axis=1)
+    qkv = jnp.einsum("bsd,de->bse", x, w)
+    if "bq" in params:
+        qkv = qkv + jnp.concatenate(
+            [params["b" + n].astype(dt).reshape(-1) for n in names])
+    head_dim = params["wq"].shape[-1]
+    cos, sin = rope_cos_sin(jnp.arange(x.shape[1]), head_dim, theta)
+    out = attention_kernels.mha_full(qkv, cos, sin, num_heads=num_heads)
+    return out.reshape(*x.shape[:2], num_heads, head_dim)
+
+
+def _attend_full_fwd(params, x, num_heads, theta):
+    return jax.vjp(
+        functools.partial(_attend_full_plain, theta=theta), params, x)
+
+
+_attend_full.defvjp(_attend_full_fwd,
+                    lambda num_heads, theta, vjp, g: vjp(g))
+
+
 def gqa_apply(params, cfg, x, *, layer_is_global: bool = True,
               positions=None, cache=None, mode: str = "train",
-              block_tables=None, paged_kernel: bool = False):
+              block_tables=None, paged_kernel: bool = False,
+              use_kernel: bool = False):
     """Returns (out, new_cache). positions: (S,) shared or (B,S) per-row
     absolute token positions; entries < 0 are pad/inactive (no cache write,
     masked from attention). With ``block_tables`` (B, blocks_per_row) the
@@ -347,20 +404,27 @@ def gqa_apply(params, cfg, x, *, layer_is_global: bool = True,
     single-token paged decode through the Pallas kernel that streams pool
     tiles in place (kernels/paged_attention_kernels.py) instead of
     gathering the per-row view — chunked prefill (S > 1) and traced
-    ``layer_is_global`` flags keep the gather fallback."""
+    ``layer_is_global`` flags keep the gather fallback.
+
+    ``use_kernel`` takes the fused full-sequence kernel
+    (kernels/attention_kernels.py) when nothing is masked: no cache, not
+    causal, no sliding window, no positions given (so every token is a
+    real one), as many kv heads as query heads, and heads and sequence
+    length the kernel ``fits``. Every other call attends
+    through ``_attend`` / ``_attend_chunked``."""
     a = cfg.attention
     b, s, _ = x.shape
+    if (use_kernel and cache is None and positions is None
+            and not cfg.causal and a.sliding_window is None
+            and a.num_kv_heads == a.num_heads
+            and attention_kernels.fits(s, a.num_heads, a.head_dim)):
+        qkv_params = {k: v for k, v in params.items() if k != "wo"}
+        out = _attend_full(qkv_params, x, a.num_heads, a.rope_theta)
+        return jnp.einsum("bshk,hkd->bsd", out,
+                          params["wo"].astype(x.dtype)), None
     if positions is None:
         positions = jnp.arange(s)
-    q = jnp.einsum("bsd,dhk->bshk", x, params["wq"].astype(x.dtype))
-    k = jnp.einsum("bsd,dgk->bsgk", x, params["wk"].astype(x.dtype))
-    v = jnp.einsum("bsd,dgk->bsgk", x, params["wv"].astype(x.dtype))
-    if a.qkv_bias:
-        q = q + params["bq"].astype(x.dtype)
-        k = k + params["bk"].astype(x.dtype)
-        v = v + params["bv"].astype(x.dtype)
-    q = apply_rope(q, positions, a.rope_theta)
-    k = apply_rope(k, positions, a.rope_theta)
+    q, k, v = _project_qkv(params, x, positions, a.rope_theta)
 
     # `layer_is_global` may be traced (scanned local:global pattern), so
     # the window is applied inside the mask rather than branched on here.
@@ -454,7 +518,8 @@ def mla_init(rng, cfg):
 
 def mla_apply(params, cfg, x, *, positions=None, cache=None,
               mode: str = "train", layer_is_global: bool = True,
-              block_tables=None, paged_kernel: bool = False):
+              block_tables=None, paged_kernel: bool = False,
+              use_kernel: bool = False):
     """MLA with compressed-KV cache. Decode uses the *absorbed* form:
     q_nope is projected into the latent rank space so attention scores are
     computed against the (B, S, rank) cache directly — no per-step
@@ -463,8 +528,10 @@ def mla_apply(params, cfg, x, *, positions=None, cache=None,
     ``paged_kernel`` is accepted for signature parity but MLA keeps the
     gather fallback: the absorbed decode attends a latent cache whose
     score/value widths differ (rank + rope vs rank), and the latent-pool
-    kernel variant is a recorded follow-up (ROADMAP)."""
-    del paged_kernel
+    kernel variant is a recorded follow-up (ROADMAP). ``use_kernel`` is
+    accepted for the same parity: MLA's full-sequence calls keep
+    ``_attend``."""
+    del paged_kernel, use_kernel
     a = cfg.attention
     b, s, _ = x.shape
     if positions is None:

@@ -77,6 +77,9 @@ def block_apply(params, cfg, x, *, is_moe: bool, is_global=True,
     paged block-pool layout (shared by every layer — all attention layers
     write the same positions); ``paged_kernel`` additionally routes paged
     single-token decode through the Pallas paged-attention kernel.
+    ``use_kernel`` routes Soft MoE through its Pallas kernels and
+    cache-less, unmasked attention through the fused full-sequence
+    kernel (``gqa_apply`` states when).
 
     ``telemetry`` is a static build flag: the extra return is a dict of
     ``stop_gradient``'d f32 scalars (attention-path absmax, residual RMS,
@@ -95,7 +98,7 @@ def block_apply(params, cfg, x, *, is_moe: bool, is_global=True,
                 layer_is_global=is_global, positions=positions,
                 cache=None if cache is None else cache.get("attn"),
                 mode=mode, block_tables=block_tables,
-                paged_kernel=paged_kernel,
+                paged_kernel=paged_kernel, use_kernel=use_kernel,
             )
         mix = mix + a_out
         if new_cache is not None:

@@ -13,6 +13,7 @@ process at a time may load the TPU library, and every test worker imports
 this file.
 """
 import collections
+import dataclasses
 import re
 
 import jax
@@ -23,7 +24,11 @@ from jax.sharding import SingleDeviceSharding
 from repro.configs import get_config
 from repro.kernels import KernelConfig, default_config, ops, paged_config
 from repro.kernels.paged_attention_kernels import paged_decode_attend
+from repro.layers.attention import gqa_apply, gqa_init
 from repro.launch.scope_time import KERNELS
+from repro.models import lm_apply, lm_init, vit_apply, vit_init
+from repro.models.lm import init_cache
+from repro.serve.block_manager import init_paged_cache
 
 # soft-moe-s/16-128e: tokens per image, width, slots
 _VIT = get_config("soft-moe-s/16-128e")
@@ -140,3 +145,98 @@ def test_paged_decode_kernel_compiles(one_chip):
     cfg = paged_config(BLOCK, KernelConfig(interpret=False))
     hlo = _kernel_hlo(lambda *a: paged_decode_attend(*a, cfg=cfg), *args)
     assert _kernel_names(hlo) == {"paged_decode_attend": 1}
+
+
+def _abstract(one_chip, tree):
+    return jax.tree.map(
+        lambda a: _shape(one_chip, a.shape, a.dtype), tree)
+
+
+@pytest.mark.parametrize("phase,b,kernels", [
+    ("forward", 512, 1), ("forward", 32, 1), ("gradient", 32, 0)])
+def test_full_attention_kernel_compiles(one_chip, monkeypatch, phase, b,
+                                        kernels):
+    """A soft-moe-s/16 attention layer with ``use_kernel`` takes the fused
+    kernel in the inference cell's forward and at the train cell's batch;
+    its gradient runs the plain path."""
+    monkeypatch.setattr("repro.kernels.tuning.backend_is_tpu", lambda: True)
+    params = _abstract(one_chip, jax.eval_shape(
+        lambda r: gqa_init(r, _VIT), jax.random.PRNGKey(0)))
+    x = _shape(one_chip, (b, M, D), jnp.bfloat16)
+
+    def fwd(p, x):
+        return gqa_apply(p, _VIT, x, use_kernel=True)[0]
+
+    fn = fwd if phase == "forward" else jax.grad(
+        lambda p, x: jnp.sum(fwd(p, x).astype(jnp.float32)))
+    names = _kernel_names(_kernel_hlo(fn, params, x))
+    assert names == ({"mha_full_fwd": kernels} if kernels else {})
+
+
+@pytest.mark.parametrize("use_kernel", [True, False])
+def test_vit_forward_routes_full_attention_kernel(one_chip, monkeypatch,
+                                                  use_kernel):
+    """The batch-512 soft-moe-s/16-128e forward holds the attention kernel
+    once in each segment's scan body (dense, then Soft MoE) with
+    ``use_kernel``, and no kernel at all without it."""
+    # Kernels resolve interpret mode from the backend, which is the CPU
+    # here: compile them for the described chip instead.
+    monkeypatch.setattr("repro.kernels.tuning.backend_is_tpu", lambda: True)
+    params = _abstract(one_chip, jax.eval_shape(
+        lambda r: vit_init(r, _VIT, num_classes=1000),
+        jax.random.PRNGKey(0)))
+    patches = _shape(one_chip, (512, M, _VIT.frontend.embed_dim))
+    hlo = _kernel_hlo(
+        lambda p, x: vit_apply(p, _VIT, x, use_kernel=use_kernel)[0],
+        params, patches)
+    names = _kernel_names(hlo)
+    if use_kernel:
+        assert names["mha_full_fwd"] == 2
+        assert names["softmoe_routing_fwd"] == 1
+    else:
+        assert not names
+
+
+def _qwen_program(phase, params, cfg, one_chip):
+    """qwen2-0.5b's cache-holding programs (prefill, paged decode) and
+    its causal training forward, every one asked for the kernels."""
+    rows, block = 4, 16
+    tokens = _shape(one_chip, (rows, 1 if phase == "paged_decode" else 64),
+                    jnp.int32)
+    if phase == "prefill":
+        cache = _abstract(one_chip, jax.eval_shape(
+            lambda: init_cache(cfg, rows, 128)))
+        fn = lambda p, t, c: lm_apply(  # noqa: E731
+            p, cfg, t, positions=jnp.arange(t.shape[1]), cache=c,
+            mode="prefill", last_only=True, use_kernel=True)[:2]
+        return fn, (params, tokens, cache)
+    if phase == "paged_decode":
+        blocks_per_row = 8
+        cache = _abstract(one_chip, jax.eval_shape(
+            lambda: init_paged_cache(cfg, rows * blocks_per_row + 1, block,
+                                     rows)))
+        pos = _shape(one_chip, (rows,), jnp.int32)
+        tables = _shape(one_chip, (rows, blocks_per_row), jnp.int32)
+        fn = lambda p, t, q, tb, c: lm_apply(  # noqa: E731
+            p, cfg, t, positions=q[:, None], cache=c, mode="decode",
+            block_tables=tb, paged_kernel=True, use_kernel=True)[:2]
+        return fn, (params, tokens, pos, tables, cache)
+    fn = lambda p, t: lm_apply(p, cfg, t, use_kernel=True)[0]  # noqa: E731
+    return fn, (params, tokens)
+
+
+@pytest.mark.parametrize("phase", ["prefill", "paged_decode", "train"])
+def test_lm_programs_hold_no_full_attention_kernel(one_chip, monkeypatch,
+                                                   phase):
+    """Causal attention, with or without a cache, never takes the fused
+    full-sequence kernel, though the call asks for kernels: qwen2-0.5b at
+    its widths, two layers deep (depth does not change the route)."""
+    monkeypatch.setattr("repro.kernels.tuning.backend_is_tpu", lambda: True)
+    cfg = dataclasses.replace(get_config("qwen2-0.5b"), num_layers=2)
+    params = _abstract(one_chip, jax.eval_shape(
+        lambda r: lm_init(r, cfg), jax.random.PRNGKey(0)))
+    fn, args = _qwen_program(phase, params, cfg, one_chip)
+    names = _kernel_names(_kernel_hlo(fn, *args))
+    assert "mha_full_fwd" not in names
+    if phase == "paged_decode":
+        assert names == {"paged_decode_attend": 2}
